@@ -85,9 +85,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x_micro, mesh: Mesh,
         outputs = jax.lax.psum(outputs * mask, stage_axis)
         return outputs
 
-    from repro.compat import import_shard_map
-    shard_map = import_shard_map()
-    fn = shard_map(stage_body, mesh=mesh,
+    fn = jax.shard_map(stage_body, mesh=mesh,
                    in_specs=(pspec, P()), out_specs=P(),
                    check_vma=False)
     return fn(params_staged, x_micro)

@@ -9,18 +9,12 @@ import jax
 from repro.kernels.flash_attention.flash_attention import flash_attention_kernel
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, S, H, D); k, v: (B, S, KVH, D) -> (B, S, H, D)."""
-    if interpret is None:
-        interpret = _default_interpret()
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

@@ -5,8 +5,8 @@ Model layout (what serving/decoder.py uses):
 Kernel layout:
     q (B, KVH, G, D);  k/v_pool (KVH, P, ps, D)
 
-On CPU (this container) the kernel runs in interpret mode; on TPU set
-``interpret=False`` (the default resolves by backend).
+The kernel is compiled for the TPU; pass ``interpret=True`` to run it
+through the Pallas interpreter on another backend.
 """
 
 from __future__ import annotations
@@ -19,15 +19,9 @@ import jax.numpy as jnp
 from repro.kernels.paged_attention.paged_attention import paged_attention_kernel
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
-                    window: int = 0, interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+                    window: int = 0, interpret: bool = False):
     B, H, D = q.shape
     P, ps, KVH, _ = k_pool.shape
     G = H // KVH

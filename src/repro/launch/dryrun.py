@@ -18,7 +18,6 @@ import jax.numpy as jnp    # noqa: E402
 import numpy as np         # noqa: E402
 
 from repro.analysis.hlo import analyze_hlo                     # noqa: E402
-from repro.compat import cost_analysis_dict                    # noqa: E402
 from repro.configs import ARCH_IDS, get_config                 # noqa: E402
 from repro.configs.shapes import SHAPES, shapes_for, skip_reason  # noqa: E402
 from repro.distributed.logical import logical_rules                 # noqa: E402
@@ -195,7 +194,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             # lint: allow(det-wallclock): host compile timing
             t_compile = time.time() - t0 - t_lower
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         ana = analyze_hlo(hlo)
         mem = _memory_dict(compiled)

@@ -10,9 +10,11 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import axis_types_kwargs
+__all__ = ["make_local_mesh", "make_production_mesh"]
 
-__all__ = ["axis_types_kwargs", "make_local_mesh", "make_production_mesh"]
+
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,14 +22,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2×16×16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(model: int = 1):
     """Whatever this process has (tests/examples: 1 CPU device)."""
     n = jax.device_count()
     return jax.make_mesh(
-        (n // model, model), ("data", "model"), **axis_types_kwargs(2))
+        (n // model, model), ("data", "model"), axis_types=_auto(2))
 
 
 # TPU v5e hardware constants (per chip) for the roofline terms

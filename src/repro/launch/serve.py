@@ -1,29 +1,47 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the continuous-batching engine over a reduced config with a paged,
-host-spillable KV pool — exercising the thesis mechanism end to end:
-admission, prefill, pool exhaustion → spill, re-activation → Touch-Ahead
-page-in, decode through the page table.
+Runs the continuous-batching engine with a paged, host-spillable KV pool
+at the config's published widths (``--reduced`` shrinks them for the CPU),
+exercising the thesis mechanism end to end: admission, prefill, pool
+exhaustion → spill, re-activation → Touch-Ahead page-in, decode through
+the page table.
 """
 
 from __future__ import annotations
 
 import argparse
+from typing import Optional
 
-import jax
 import numpy as np
 
 from repro.api import FaultPolicy, Strategy
 from repro.configs import ARCH_IDS, get_config
-from repro.models.config import reduced
-from repro.models.registry import model_for
+from repro.launch.common import random_params, use_compile_cache
+from repro.models.config import ModelConfig, reduced
 from repro.serving.engine import ServingEngine
 from repro.serving.sampler import SamplerConfig
 
 
+def make_engine(cfg: ModelConfig, params, *, max_batch: int, max_len: int,
+                pool_frames: Optional[int] = None,
+                strategy: Strategy = Strategy.TOUCH_AHEAD,
+                lookahead: int = 4, pin_all: bool = False,
+                temperature: float = 0.0) -> ServingEngine:
+    """``pool_frames=None`` sizes the frame pool exactly for
+    ``max_batch`` sequences of ``max_len`` tokens; fewer frames force
+    spills and fault-ins."""
+    policy = FaultPolicy(strategy=strategy, lookahead=lookahead)
+    return ServingEngine(
+        cfg, params, max_batch=max_batch, max_len=max_len,
+        pool_frames=pool_frames, policy=policy, pin_all=pin_all,
+        sampler=SamplerConfig(temperature=temperature))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen3_14b", choices=ARCH_IDS)
+    ap.add_argument("--arch", default="starcoder2_3b", choices=ARCH_IDS)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family widths (for the CPU)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=2)
@@ -39,16 +57,15 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
 
-    cfg = reduced(get_config(args.arch))
-    model = model_for(cfg)
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
-    policy = FaultPolicy(strategy=Strategy(args.strategy),
-                         lookahead=args.lookahead)
-    eng = ServingEngine(
-        cfg, params, max_batch=args.max_batch, max_len=args.max_len,
-        pool_frames=args.pool_frames or None,
-        policy=policy, pin_all=args.pin_all,
-        sampler=SamplerConfig(temperature=args.temperature))
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
+    eng = make_engine(
+        cfg, random_params(cfg, 0), max_batch=args.max_batch,
+        max_len=args.max_len, pool_frames=args.pool_frames or None,
+        strategy=Strategy(args.strategy), lookahead=args.lookahead,
+        pin_all=args.pin_all, temperature=args.temperature)
 
     rng = np.random.default_rng(0)
     reqs = [eng.submit(rng.integers(0, cfg.vocab_size, size=rng.integers(3, 9)),

@@ -17,8 +17,8 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import ShardInfo, SyntheticLM
 from repro.distributed.checkpoint import Checkpointer
+from repro.launch.common import random_params, use_compile_cache
 from repro.models.config import reduced
-from repro.models.registry import model_for
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedules import cosine_with_warmup
 from repro.training.trainer import TrainConfig, Trainer
@@ -41,12 +41,12 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg, n_layers=4, d_model=128, d_ff=256 if cfg.d_ff else 0,
                       vocab_size=512)
-    model = model_for(cfg)
-    params = model.init_params(cfg, jax.random.PRNGKey(args.seed))
+    params = random_params(cfg, args.seed)
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     print(f"arch={cfg.name} family={cfg.family} params={n_params:,}")
 

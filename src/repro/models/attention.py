@@ -82,8 +82,8 @@ def _paged_update_and_attend(q1, k1, v1, k_pool, v_pool, page_table,
     offset = pos % ps
     frame = jnp.take_along_axis(page_table, page_slot[:, None], axis=1)[:, 0]
     frame = jnp.maximum(frame, 0)
-    k_pool = k_pool.at[frame, offset[0]].set(k1)
-    v_pool = v_pool.at[frame, offset[0]].set(v1)
+    k_pool = k_pool.at[frame, offset].set(k1)
+    v_pool = v_pool.at[frame, offset].set(v1)
     out = paged_attention_xla(q1, k_pool, v_pool, page_table, lengths,
                               window=window)
     return out, k_pool, v_pool
@@ -101,8 +101,6 @@ def _paged_update_and_attend_dist(q1, k1, v1, k_pool, v_pool, page_table,
     shard_map the gather is local: page-table frames are rebased to the
     shard-local pool slice and no collective is emitted at all.
     """
-    from repro.compat import import_shard_map
-    shard_map = import_shard_map()
     from jax.sharding import PartitionSpec as P
     import numpy as _np
     from repro.distributed import logical
@@ -138,7 +136,7 @@ def _paged_update_and_attend_dist(q1, k1, v1, k_pool, v_pool, page_table,
 
     d = daxes
     h = "model" if head_tp else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(d, h), P(d, h), P(d, h),
                   P(d, None, h), P(d, None, h), P(d), P(d)),
@@ -152,9 +150,9 @@ def apply_attention_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool,
     """One-token decode through the paged KV pool.
 
     x: (B, 1, d).  ``lengths`` counts tokens *including* the current one.
-    The new token's K/V is written into its page (uniform offset across the
-    batch — the shapes' decode steps are in lockstep), then attention reads
-    the whole context through the page table.
+    Each row's new K/V is written at that row's own position (rows of a
+    continuous batch hold sequences of different lengths), then attention
+    reads the whole context through the page table.
     Returns (out, k_pool, v_pool).
     """
     B = x.shape[0]
@@ -181,9 +179,9 @@ def apply_attention_decode_ring(p, cfg: ModelConfig, x, k_ring, v_ring,
     pos = lengths - 1
     q, k, v = _qkv(p, cfg, x, pos[:, None])
     q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
-    slot = pos[0] % W
-    k_ring = jax.lax.dynamic_update_slice_in_dim(k_ring, k1[:, None], slot, 1)
-    v_ring = jax.lax.dynamic_update_slice_in_dim(v_ring, v1[:, None], slot, 1)
+    rows, slot = jnp.arange(B), pos % W
+    k_ring = k_ring.at[rows, slot].set(k1)
+    v_ring = v_ring.at[rows, slot].set(v1)
     out = ring_buffer_attention(q1, k_ring, v_ring, lengths, cfg.sliding_window)
     out = out.reshape(B, cfg.n_heads * cfg.head_dim) @ p["wo"]
     return out[:, None, :], k_ring, v_ring

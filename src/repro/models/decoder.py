@@ -71,14 +71,16 @@ def init_params(cfg: ModelConfig, key) -> dict:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size,
                                        dtype)
+
+    def stacked(layer_keys, moe):
+        # made stacked (one layer per key), never as per-layer copies
+        return jax.vmap(lambda k: _init_layer(k, cfg, dtype, moe))(layer_keys)
+
     if n_dense:
-        params["dense_layers"] = _stack(
-            [_init_layer(keys[2 + i], cfg, dtype, moe=False)
-             for i in range(n_dense)])
+        params["dense_layers"] = stacked(keys[2:2 + n_dense], moe=False)
     if n_moe:
-        params["moe_layers"] = _stack(
-            [_init_layer(keys[2 + n_dense + i], cfg, dtype, moe=True)
-             for i in range(n_moe)])
+        params["moe_layers"] = stacked(keys[2 + n_dense:2 + cfg.n_layers],
+                                       moe=True)
     if cfg.mtp_depth:
         params["mtp"] = _stack(
             [_init_layer(keys[2 + cfg.n_layers + 0], cfg, dtype,

@@ -88,8 +88,8 @@ def _mla_update_and_attend(q_abs, q_rope, c_new, kr_new, ckv_pool,
     offset = pos % ps
     frame = jnp.take_along_axis(page_table, page_slot[:, None], axis=1)[:, 0]
     frame = jnp.maximum(frame, 0)
-    ckv_pool = ckv_pool.at[frame, offset[0]].set(c_new)
-    krope_pool = krope_pool.at[frame, offset[0]].set(kr_new)
+    ckv_pool = ckv_pool.at[frame, offset].set(c_new)
+    krope_pool = krope_pool.at[frame, offset].set(kr_new)
     max_pages = page_table.shape[1]
 
     def page_step(carry, j):
@@ -129,8 +129,6 @@ def _mla_update_and_attend_dist(q_abs, q_rope, c_new, kr_new, ckv_pool,
     transit the region replicated over 'model', one layer slice at a time).
     Same locality argument as the GQA path (EXPERIMENTS.md §Perf iter. 5).
     """
-    from repro.compat import import_shard_map
-    shard_map = import_shard_map()
     from jax.sharding import PartitionSpec as P
     import numpy as _np
     from repro.distributed import logical
@@ -162,7 +160,7 @@ def _mla_update_and_attend_dist(q_abs, q_rope, c_new, kr_new, ckv_pool,
                                       scale=scale)
 
     d = daxes
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(d, h), P(d, h), P(d), P(d), P(d), P(d), P(d), P(d)),
         out_specs=(P(d, h), P(d), P(d)),

@@ -19,6 +19,7 @@ refuses admission beyond it (the thesis' memory-utilization cost).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -33,6 +34,14 @@ from repro.vmem import coerce_policy
 from repro.models.config import ModelConfig
 from repro.models.registry import model_for
 from repro.serving.sampler import SamplerConfig, sample_token
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _step(params, cfg: ModelConfig, cache, tokens):
+    """The compiled one-token step, shared by every engine on ``cfg``:
+    decode at ``(max_batch, 1)`` and prefill at ``(1, 1)`` are its two
+    shapes, each compiled once per process."""
+    return model_for(cfg).decode_step(params, cfg, cache, tokens)
 
 
 @dataclasses.dataclass
@@ -88,11 +97,10 @@ class ServingEngine:
         # accumulation cursors into the shared vmem PagingStats
         self._kv_us_seen = 0.0
         self._kv_spills_seen = 0
-        # compiled decode step: fixed (max_batch) shape; cache pools sized
-        # to the device pool (shared across the batch via page table)
+        # fixed (max_batch) decode shape; cache pools sized to the device
+        # pool (shared across the batch via page table)
         self.cache = self.model.init_decode_cache(cfg, max_batch, max_len)
-        self._decode = jax.jit(
-            lambda p, c, t: self.model.decode_step(p, cfg, c, t))
+        self._seq_caches: dict = {}
         self.queue: list[Request] = []
         self.active: list[Request] = []
         self.req_counter = 0
@@ -125,19 +133,16 @@ class ServingEngine:
             self.stats.prefills += 1
 
     def _prefill_sequence(self, r: Request) -> None:
-        """Token-by-token prefill through the decode step (batch slot 0).
+        """Token-by-token prefill of all but the last prompt token, through
+        the engine's compiled step at batch 1.
 
-        Keeps one compiled program for the whole engine; production TPU
-        deployments add a chunked prefill program — see serving docs.
+        The first decode step feeds the last prompt token and samples the
+        first new one, so every token enters the cache exactly once.
         """
-        slot_cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
-        step = jax.jit(
-            lambda p, c, t: self.model.decode_step(p, self.cfg, c, t))
-        cache = slot_cache
-        for t in r.prompt:
-            _, cache = step(self.params, cache,
-                            jnp.asarray([[t]], jnp.int32))
-        self._seq_caches = getattr(self, "_seq_caches", {})
+        cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
+        for t in r.prompt[:-1]:
+            _, cache = _step(self.params, self.cfg, cache,
+                             jnp.asarray([[t]], jnp.int32))
         self._seq_caches[r.req_id] = cache
 
     # -------------------------------------------------------------- decode
@@ -207,13 +212,14 @@ class ServingEngine:
             last = r.generated[-1] if r.generated else r.prompt[-1]
             tokens[i, 0] = last
         cache = self._gather_batch_cache(batch)
-        logits, cache = self._decode(self.params, cache,
-                                     jnp.asarray(tokens))
+        logits, cache = _step(self.params, self.cfg, cache,
+                              jnp.asarray(tokens))
         self.stats.decode_steps += 1
         key = jax.random.PRNGKey(self.stats.decode_steps)
         next_tokens = sample_token(logits[:, 0] if logits.ndim == 3
                                    else logits, self.sampler, key)
         # scatter results + updated caches back per sequence
+        cache = jax.tree_util.tree_map(np.asarray, cache)
         for i, r in enumerate(batch):
             tok = int(next_tokens[i])
             r.generated.append(tok)
@@ -224,10 +230,9 @@ class ServingEngine:
             out = []
             for path, leaf in flat:
                 name = self._path_str(path)
-                sub = cache
+                big = cache
                 for p in path:
-                    sub = sub[getattr(p, "key", getattr(p, "idx", None))]
-                big = np.asarray(sub)
+                    big = big[getattr(p, "key", getattr(p, "idx", None))]
                 if name == "lengths":
                     out.append(leaf + 1)
                 elif "pool" in name:

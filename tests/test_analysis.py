@@ -7,7 +7,6 @@ import pytest
 
 from repro.analysis.hlo import analyze_hlo, shape_bytes, shape_elems
 from repro.distributed.logical import logical_rules, spec_for, constrain
-from repro.launch.mesh import axis_types_kwargs
 
 
 class TestShapeParsing:
@@ -66,7 +65,7 @@ class TestAnalyzeHLO:
 class TestLogicalRules:
     def _mesh(self):
         return jax.make_mesh((1, 1), ("data", "model"),
-                             **axis_types_kwargs(2))
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     def test_noop_without_policy(self):
         x = jnp.ones((4, 8))
@@ -74,7 +73,7 @@ class TestLogicalRules:
 
     def test_divisibility_drops_axis(self):
         mesh = jax.make_mesh(
-            (1, 1), ("data", "model"), **axis_types_kwargs(2))
+            (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with logical_rules(mesh, {"heads": "model", "batch": "data"}):
             # heads=24 % model size 1 == 0 -> kept (size-1 axis trivially ok)
             spec = spec_for((2, 24), ("batch", "heads"))

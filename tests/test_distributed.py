@@ -140,9 +140,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_apply
 
-from repro.launch.mesh import axis_types_kwargs
 mesh = jax.make_mesh((4,), ("stage",),
-                     **axis_types_kwargs(1))
+                     axis_types=(jax.sharding.AxisType.Auto,))
 L, D = 8, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, D, D)) * 0.3

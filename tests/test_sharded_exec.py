@@ -43,9 +43,8 @@ cache0["lengths"] = jnp.full((B,), 9, jnp.int32)   # mid-context decode
 ref_logits, ref_cache = model.decode_step(params, cfg, cache0, tokens)
 
 # ---- distributed: mesh (2 data x 4 model), shard_map paged decode --------
-from repro.launch.mesh import axis_types_kwargs
 mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     **axis_types_kwargs(2))
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = {"batch": "data", "heads": "model", "kv_heads": "model",
          "ff": "model"}
 p_sh = param_shardings(params, mesh)
