@@ -77,8 +77,7 @@ def main() -> None:
     s = eng.stats
     print(f"\nstats: prefills={s.prefills} decode_steps={s.decode_steps} "
           f"tokens={s.tokens_generated} spills={s.spill_events} "
-          f"fault_page_ins={s.fault_page_ins} "
-          f"sim_fault_us={s.simulated_fault_us:.1f}")
+          f"fault_page_ins={s.fault_page_ins}")
 
 
 if __name__ == "__main__":

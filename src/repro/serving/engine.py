@@ -14,6 +14,14 @@ The thesis' runtime loop, applied to inference serving:
 
 Pinning baseline: ``pin_all=True`` sizes residency for the worst case and
 refuses admission beyond it (the thesis' memory-utilization cost).
+
+Host spans (``jax.profiler.TraceAnnotation``, recorded only while a
+profiler trace runs, on the device trace's clock): ``serve.step`` around
+each ``step_decode`` (``step``), inside it ``serve.admit``,
+``serve.prefill`` (``req_id``, ``prompt_tokens``), ``serve.gather``,
+``serve.dispatch`` (the step program and sampling), ``serve.scatter`` and
+``serve.retire``, and ``serve.pager`` around every call into the KV pager
+(``op``, ``req_id``, and ``pages`` faulted in by ``ensure_resident``).
 """
 
 from __future__ import annotations
@@ -25,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.policy import FaultPolicy
 from repro.core.arbiter import ServiceClass
@@ -56,11 +65,14 @@ class Request:
 @dataclasses.dataclass
 class EngineStats:
     prefills: int = 0
+    prefill_tokens: int = 0         # prompt tokens fed by batch-1 prefill
     decode_steps: int = 0
     tokens_generated: int = 0
     spill_events: int = 0
     fault_page_ins: int = 0
-    simulated_fault_us: float = 0.0
+    # bytes of every array the batch gather and scatter convert between
+    # device and numpy, either way, counted per conversion from its size
+    cache_host_bytes: int = 0
 
 
 class ServingEngine:
@@ -94,8 +106,7 @@ class ServingEngine:
         self.kv = PagedKVManager(n_frames, ps, pages_per_seq,
                                  policy=self.policy)
         self.stats = EngineStats()
-        # accumulation cursors into the shared vmem PagingStats
-        self._kv_us_seen = 0.0
+        # accumulation cursor into the shared vmem PagingStats
         self._kv_spills_seen = 0
         # fixed (max_batch) decode shape; cache pools sized to the device
         # pool (shared across the batch via page table)
@@ -113,24 +124,34 @@ class ServingEngine:
         self.queue.append(r)
         return r
 
+    # --------------------------------------------------------------- pager
+    def _pager(self, op: str, req_id: int, *args, **kwargs):
+        """``self.kv.<op>(req_id, ...)`` under a ``serve.pager`` span."""
+        with TraceAnnotation("serve.pager", op=op, req_id=req_id) as span:
+            out = getattr(self.kv, op)(req_id, *args, **kwargs)
+            if op == "ensure_resident":
+                span.set_metadata(pages=out)
+        return out
+
     # ------------------------------------------------------------- prefill
     def _admit(self) -> None:
-        while self.queue and len(self.active) < self.max_batch:
-            r = self.queue.pop(0)
-            need_pages = -(-(len(r.prompt) + r.max_new_tokens)
-                           // self.kv.page_tokens)
-            if self.pin_all and self.kv.frames_used + need_pages > \
-                    self.kv.n_frames:
-                self.queue.insert(0, r)     # admission control: refuse
-                break
-            self.kv.add_sequence(r.req_id)
-            waiting = [q.req_id for q in self.queue
-                       if q.req_id in self.kv.seq_spaces]
-            self.kv.append_tokens(r.req_id, len(r.prompt),
-                                  spill_candidates=waiting)
-            self._prefill_sequence(r)
-            self.active.append(r)
-            self.stats.prefills += 1
+        with TraceAnnotation("serve.admit"):
+            while self.queue and len(self.active) < self.max_batch:
+                r = self.queue.pop(0)
+                need_pages = -(-(len(r.prompt) + r.max_new_tokens)
+                               // self.kv.page_tokens)
+                if self.pin_all and self.kv.frames_used + need_pages > \
+                        self.kv.n_frames:
+                    self.queue.insert(0, r)     # admission control: refuse
+                    break
+                self._pager("add_sequence", r.req_id)
+                waiting = [q.req_id for q in self.queue
+                           if q.req_id in self.kv.seq_spaces]
+                self._pager("append_tokens", r.req_id, len(r.prompt),
+                            spill_candidates=waiting)
+                self._prefill_sequence(r)
+                self.active.append(r)
+                self.stats.prefills += 1
 
     def _prefill_sequence(self, r: Request) -> None:
         """Token-by-token prefill of all but the last prompt token, through
@@ -139,17 +160,33 @@ class ServingEngine:
         The first decode step feeds the last prompt token and samples the
         first new one, so every token enters the cache exactly once.
         """
-        cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
-        for t in r.prompt[:-1]:
-            _, cache = _step(self.params, self.cfg, cache,
-                             jnp.asarray([[t]], jnp.int32))
-        self._seq_caches[r.req_id] = cache
+        with TraceAnnotation("serve.prefill", req_id=r.req_id,
+                             prompt_tokens=len(r.prompt)):
+            cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
+            for t in r.prompt[:-1]:
+                _, cache = _step(self.params, self.cfg, cache,
+                                 jnp.asarray([[t]], jnp.int32))
+            self._seq_caches[r.req_id] = cache
+            self.stats.prefill_tokens += len(r.prompt) - 1
 
     # -------------------------------------------------------------- decode
     @staticmethod
     def _path_str(path) -> str:
         return "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                         for p in path)
+
+    def _to_host(self, x, copy: bool = False) -> np.ndarray:
+        """``x`` as a numpy array (a writable copy with ``copy``), its
+        bytes counted in ``cache_host_bytes``."""
+        out = np.array(x) if copy else np.asarray(x)
+        self.stats.cache_host_bytes += out.nbytes
+        return out
+
+    def _to_device(self, x: np.ndarray) -> jax.Array:
+        """``x`` as a device array, its bytes counted in
+        ``cache_host_bytes``."""
+        self.stats.cache_host_bytes += x.nbytes
+        return jnp.asarray(x)
 
     def _gather_batch_cache(self, batch: list[Request]):
         """Merge per-sequence caches into the fixed-batch decode cache.
@@ -164,12 +201,12 @@ class ServingEngine:
         out = []
         for path, full in flat:
             name = self._path_str(path)
-            arr = np.array(full)
+            arr = self._to_host(full, copy=True)
             for i in range(len(batch)):
                 sub = caches[i]
                 for p in path:
                     sub = sub[getattr(p, "key", getattr(p, "idx", None))]
-                part = np.asarray(sub)
+                part = self._to_host(sub)
                 if name == "lengths":
                     arr[i] = part[0]
                 elif "pool" in name:
@@ -179,82 +216,85 @@ class ServingEngine:
                     pass   # identity table already maps slot -> its range
                 else:
                     arr[:, i] = part[:, 0]
-            out.append(jnp.asarray(arr))
+            out.append(self._to_device(arr))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def step_decode(self) -> int:
         """One lockstep decode over all active sequences."""
-        self._admit()
-        if not self.active:
-            return 0
-        batch = self.active[:self.max_batch]
-        # residency: fault spilled pages back in before dispatch
-        waiting = [q.req_id for q in self.queue
-                   if q.req_id in self.kv.seq_spaces]
-        for r in batch:
-            n = self.kv.ensure_resident(r.req_id, spill_candidates=waiting)
-            self.stats.fault_page_ins += n
-        # accumulate deltas from the shared PagingStats (the pager keeps
-        # the source of truth; EngineStats no longer aliases it); a
-        # negative delta means someone reset() the shared stats — the
-        # post-reset total IS the delta then
-        kv = self.kv.stats
-        d_us = kv.simulated_us - self._kv_us_seen
-        self.stats.simulated_fault_us += d_us if d_us >= 0 \
-            else kv.simulated_us
-        self._kv_us_seen = kv.simulated_us
-        d_sp = kv.spills - self._kv_spills_seen
-        self.stats.spill_events += d_sp if d_sp >= 0 else kv.spills
-        self._kv_spills_seen = kv.spills
+        with TraceAnnotation("serve.step", step=self.stats.decode_steps + 1):
+            self._admit()
+            if not self.active:
+                return 0
+            batch = self.active[:self.max_batch]
+            # residency: fault spilled pages back in before dispatch
+            waiting = [q.req_id for q in self.queue
+                       if q.req_id in self.kv.seq_spaces]
+            for r in batch:
+                self.stats.fault_page_ins += self._pager(
+                    "ensure_resident", r.req_id, spill_candidates=waiting)
+            # accumulate deltas from the shared PagingStats (the pager keeps
+            # the source of truth; EngineStats no longer aliases it); a
+            # negative delta means someone reset() the shared stats — the
+            # post-reset total IS the delta then
+            kv = self.kv.stats
+            d_sp = kv.spills - self._kv_spills_seen
+            self.stats.spill_events += d_sp if d_sp >= 0 else kv.spills
+            self._kv_spills_seen = kv.spills
 
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for i, r in enumerate(batch):
-            last = r.generated[-1] if r.generated else r.prompt[-1]
-            tokens[i, 0] = last
-        cache = self._gather_batch_cache(batch)
-        logits, cache = _step(self.params, self.cfg, cache,
-                              jnp.asarray(tokens))
-        self.stats.decode_steps += 1
-        key = jax.random.PRNGKey(self.stats.decode_steps)
-        next_tokens = sample_token(logits[:, 0] if logits.ndim == 3
-                                   else logits, self.sampler, key)
-        # scatter results + updated caches back per sequence
-        cache = jax.tree_util.tree_map(np.asarray, cache)
-        for i, r in enumerate(batch):
-            tok = int(next_tokens[i])
-            r.generated.append(tok)
-            self.kv.append_tokens(r.req_id, 1)
-            self.stats.tokens_generated += 1
-            seq_cache = self._seq_caches[r.req_id]
-            flat, treedef = jax.tree_util.tree_flatten_with_path(seq_cache)
-            out = []
-            for path, leaf in flat:
-                name = self._path_str(path)
-                big = cache
-                for p in path:
-                    big = big[getattr(p, "key", getattr(p, "idx", None))]
-                if name == "lengths":
-                    out.append(leaf + 1)
-                elif "pool" in name:
-                    per_seq = np.asarray(leaf).shape[1]
-                    out.append(jnp.asarray(
-                        big[:, i * per_seq:(i + 1) * per_seq]))
-                elif "table" in name:
-                    out.append(leaf)
-                else:
-                    arr = np.array(leaf)
-                    arr[:, 0] = big[:, i]
-                    out.append(jnp.asarray(arr))
-            self._seq_caches[r.req_id] = jax.tree_util.tree_unflatten(
-                treedef, out)
-            if len(r.generated) >= r.max_new_tokens:
-                r.done = True
-        finished = [r for r in batch if r.done]
-        for r in finished:
-            self.active.remove(r)
-            self.kv.free_sequence(r.req_id)
-            self._seq_caches.pop(r.req_id, None)
-        return len(batch)
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            for i, r in enumerate(batch):
+                last = r.generated[-1] if r.generated else r.prompt[-1]
+                tokens[i, 0] = last
+            with TraceAnnotation("serve.gather"):
+                cache = self._gather_batch_cache(batch)
+            with TraceAnnotation("serve.dispatch"):
+                logits, cache = _step(self.params, self.cfg, cache,
+                                      jnp.asarray(tokens))
+                self.stats.decode_steps += 1
+                key = jax.random.PRNGKey(self.stats.decode_steps)
+                next_tokens = sample_token(logits[:, 0] if logits.ndim == 3
+                                           else logits, self.sampler, key)
+            # scatter results + updated caches back per sequence
+            with TraceAnnotation("serve.scatter"):
+                cache = jax.tree_util.tree_map(self._to_host, cache)
+                for i, r in enumerate(batch):
+                    tok = int(next_tokens[i])
+                    r.generated.append(tok)
+                    self._pager("append_tokens", r.req_id, 1)
+                    self.stats.tokens_generated += 1
+                    seq_cache = self._seq_caches[r.req_id]
+                    flat, treedef = jax.tree_util.tree_flatten_with_path(
+                        seq_cache)
+                    out = []
+                    for path, leaf in flat:
+                        name = self._path_str(path)
+                        big = cache
+                        for p in path:
+                            big = big[getattr(p, "key",
+                                              getattr(p, "idx", None))]
+                        if name == "lengths":
+                            out.append(leaf + 1)
+                        elif "pool" in name:
+                            per_seq = self._to_host(leaf).shape[1]
+                            out.append(self._to_device(
+                                big[:, i * per_seq:(i + 1) * per_seq]))
+                        elif "table" in name:
+                            out.append(leaf)
+                        else:
+                            arr = self._to_host(leaf, copy=True)
+                            arr[:, 0] = big[:, i]
+                            out.append(self._to_device(arr))
+                    self._seq_caches[r.req_id] = \
+                        jax.tree_util.tree_unflatten(treedef, out)
+                    if len(r.generated) >= r.max_new_tokens:
+                        r.done = True
+            with TraceAnnotation("serve.retire"):
+                finished = [r for r in batch if r.done]
+                for r in finished:
+                    self.active.remove(r)
+                    self._pager("free_sequence", r.req_id)
+                    self._seq_caches.pop(r.req_id, None)
+            return len(batch)
 
     def run_until_done(self, max_steps: int = 10_000) -> None:
         steps = 0
