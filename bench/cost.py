@@ -1,12 +1,14 @@
-"""Operations and bytes that one call of the one-token step needs.
+"""Operations and bytes that the model work of serving needs: one decode
+step over a batch of rows, and the prefill of one prompt.
 
-The work a call needs, not what a particular lowering does: every weight
-read once, the new token's K/V written once, and the K/V of the context
-that attention may see (the window bounds it) read once.  A lowering
-that copies the whole cache, or reads pages past a sequence's length,
-does more than this and so reads below 100% of its roofline.  Norm
-arithmetic, rotary embedding and softmax are left out; they are a few
-operations per element against the thousands of a matrix product.
+The work, not what a particular lowering does: every weight read once per
+step or per prompt, each new token's K/V written once, and in a decode
+step the K/V of the context that attention may see (the window bounds it)
+read once.  A lowering that copies the whole cache, reads pages past a
+sequence's length, or feeds a prompt one token at a time does more than
+this and so reads below 100% of its roofline.  Norm arithmetic, rotary
+embedding and softmax are left out; they are a few operations per element
+against the thousands of a matrix product.
 """
 
 from __future__ import annotations
@@ -62,22 +64,60 @@ def step_flops(m: ModelSpec, contexts: Sequence[int]) -> int:
     return sum(token_flops(m, c) for c in contexts)
 
 
-def step_bytes(m: ModelSpec, contexts: Sequence[int]) -> int:
-    """Bytes one call needs to move: the weights once (of the embedding
-    table only the rows looked up), each row's visible K/V read and its
-    new K/V written, and the logits written."""
-    b = m.dtype_bytes
-    weights = param_bytes(m) - m.vocab * m.d_model * b
+def weight_bytes(m: ModelSpec) -> int:
+    """Weights read once: every parameter but the embedding table, whose
+    rows are counted where they are looked up, and the table whole where it
+    is the tied LM head."""
+    weights = param_bytes(m) - m.vocab * m.d_model * m.dtype_bytes
     if m.tie_embeddings:          # the head is the table, read whole
-        weights += m.vocab * m.d_model * b
+        weights += m.vocab * m.d_model * m.dtype_bytes
+    return weights
+
+
+def step_bytes(m: ModelSpec, contexts: Sequence[int]) -> int:
+    """Bytes one call needs to move: the weights once, each row's embedding
+    row, its visible K/V read and its new K/V written, and the logits
+    written."""
+    b = m.dtype_bytes
     rows = len(contexts)
     kv = kv_bytes_per_token(m)
     kv_moved = sum(visible(m, c) for c in contexts) * kv + rows * kv
-    return weights + rows * m.d_model * b + kv_moved + rows * m.vocab * b
+    return weight_bytes(m) + rows * m.d_model * b + kv_moved \
+        + rows * m.vocab * b
+
+
+def prefill_flops(m: ModelSpec, n: int) -> int:
+    """Prefill of a prompt of ``n`` tokens: its first ``n - 1`` tokens, each
+    at its own context; the last enters through a decode step as a row."""
+    return sum(token_flops(m, c) for c in range(1, n))
+
+
+def prefill_bytes(m: ModelSpec, n: int) -> int:
+    """Bytes the prefill of a prompt of ``n`` tokens needs to move: the
+    weights once, the ``n - 1`` embedding rows, and their K/V written
+    once.  A prompt of one token needs no prefill pass."""
+    rows = n - 1
+    if rows <= 0:
+        return 0
+    return weight_bytes(m) + rows * m.d_model * m.dtype_bytes \
+        + rows * kv_bytes_per_token(m)
+
+
+def least_seconds(flops: int, nbytes: int, peaks: dict) -> float:
+    """Least time the chip could take for work of ``flops`` operations that
+    moves ``nbytes``: the larger of the two at the chip's peaks."""
+    return max(flops / peaks["bf16_flops_per_s"],
+               nbytes / peaks["hbm_bytes_per_s"])
 
 
 def roofline_seconds(m: ModelSpec, contexts: Sequence[int],
                      peaks: dict) -> float:
     """Least time the chip could take for one call."""
-    return max(step_flops(m, contexts) / peaks["bf16_flops_per_s"],
-               step_bytes(m, contexts) / peaks["hbm_bytes_per_s"])
+    return least_seconds(step_flops(m, contexts), step_bytes(m, contexts),
+                         peaks)
+
+
+def prefill_seconds(m: ModelSpec, n: int, peaks: dict) -> float:
+    """Least time the chip could take to prefill a prompt of ``n``
+    tokens."""
+    return least_seconds(prefill_flops(m, n), prefill_bytes(m, n), peaks)

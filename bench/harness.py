@@ -141,11 +141,10 @@ def program_config(config: dict[str, Any]):
 
 
 def _stats(eng) -> dict[str, int]:
-    s = eng.stats
-    return {"prefills": s.prefills, "decode_steps": s.decode_steps,
-            "tokens_generated": s.tokens_generated,
-            "spill_events": s.spill_events,
-            "fault_page_ins": s.fault_page_ins}
+    """Every integer counter of the engine's stats; one the program lacks
+    is absent, so that its readers find nothing."""
+    return {k: v for k, v in dataclasses.asdict(eng.stats).items()
+            if isinstance(v, int)}
 
 
 def _check_set(w: loop.Window, seed: int) -> list[loop.Sent]:
@@ -255,8 +254,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         t0 = time.perf_counter()
         reduction = tracer.stop()
         log(f"trace: steps={len(window.traced_steps)} "
-            f"step_programs={reduction.step_programs} "
-            f"window_s={reduction.window_s} read_s={time.perf_counter() - t0} "
+            f"window_s={reduction.window_s} busy_s={reduction.busy_s} "
+            f"read_s={time.perf_counter() - t0} "
             f"{tracer.seconds}")
     compiles_in_window = clock.compiles - compiles0
     counters = {k: v - stats0[k] for k, v in _stats(eng).items()}

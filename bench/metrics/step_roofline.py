@@ -1,33 +1,29 @@
-"""Share of its roofline that the one-token step program reaches: the least
-time the chip could take for every ``_step`` call in the traced window
+"""Share of the traced window's device busy time that the model work of its
+steps needs at the least: the least time the chip could take for that work
 (``bench/cost.py``: the larger of operations over peak and needed bytes
-over bandwidth, per call), over the device time of the ``jit__step``
-programs in the trace, which holds the window's last steps whole.  Moves
-``out_tok_per_s``."""
+over bandwidth), over the union of the device's operation intervals in the
+traced window (``Reduction.busy_s``).  Moves ``out_tok_per_s``.
 
-from bench.cost import roofline_seconds
+The work of a traced step is one prefill pass over the first ``n - 1``
+tokens of each prompt of ``n`` tokens it admitted, and one decode call over
+its rows at their contexts.  It is counted from the steps alone, whatever
+programs the system runs it as, and however many: a prefill that feeds its
+prompt one token at a time does the weights' reads once per token, and
+reads that much lower.
+"""
 
-
-def calls(steps):
-    """Contexts of every ``_step`` call these steps made: each admitted
-    prompt's batch-1 prefill calls, then each step's batched decode."""
-    for s in steps:
-        for n in s.admitted:
-            for c in range(1, n):
-                yield [c]
-        yield s.contexts
+from bench.cost import prefill_seconds, roofline_seconds
 
 
 def read(run):
     t = run.trace
-    if t is None or t.step_device_s <= 0:
+    steps = run.window.traced_steps
+    if t is None or t.busy_s <= 0 or not steps:
         return None
     need = 0.0
-    n = 0
-    for ctx in calls(run.window.traced_steps):
-        need += roofline_seconds(run.model, ctx, run.peaks)
-        n += 1
-    if n != t.step_programs:
-        raise RuntimeError(f"the trace holds {t.step_programs} step "
-                           f"programs, its steps made {n} calls")
-    return 100.0 * need / t.step_device_s
+    for s in steps:
+        need += sum(prefill_seconds(run.model, n, run.peaks)
+                    for n in s.admitted)
+        if s.contexts:
+            need += roofline_seconds(run.model, s.contexts, run.peaks)
+    return 100.0 * need / t.busy_s

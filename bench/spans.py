@@ -58,7 +58,7 @@ def load(path: str) -> dict[str, Any]:
                 line.name: [[trace._short(e.name), e.start_ns, e.duration_ns]
                             for e in line.events]
                 for line in plane.lines
-                if line.name in (trace.OPS_LINE, trace.MODULES_LINE)}})
+                if line.name == trace.OPS_LINE}})
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 found += [[e.name, e.start_ns, e.duration_ns]

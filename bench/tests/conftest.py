@@ -62,6 +62,17 @@ def small_config(arch: str, **widths) -> dict:
     return file_config(arch, **over)
 
 
+def tiny_spec(window: int = 0):
+    """A model small enough to count its costs by hand: per layer 192
+    attention and 256 MLP weights, a tied head of 8 × 32, bf16."""
+    from bench.model_spec import ModelSpec
+    return ModelSpec(n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
+                     head_dim=4, d_ff=16, vocab=32, rope_theta=1e4,
+                     norm="layer_norm", norm_eps=1e-5, mlp="gelu_tanh",
+                     bias=False, window=window, tie_embeddings=True,
+                     dtype="bfloat16")
+
+
 def make_root(tmp: Path, configs: dict[str, dict], mixes: dict[str, dict],
               cells: list[tuple[str, str, str]], limit: float) -> Path:
     """A benchmark root under ``tmp``: this repo's ``bench/`` and

@@ -14,7 +14,7 @@ from bench.harness import program_config
 from bench.model_spec import ModelSpec
 from bench.spec import BENCH_DIR
 
-from .conftest import file_config
+from .conftest import file_config, tiny_spec
 
 CONFIGS = {"starcoder2_3b": 30_720, "h2o_danube_1_8b": 61_440}
 # parameters of the published checkpoints, in billions to two places (the
@@ -98,3 +98,33 @@ def test_window_bounds_attention():
     assert cost.token_flops(m, 5000) == cost.token_flops(m, 4096)
     assert np.isclose(cost.token_flops(m, 1000) - cost.token_flops(m, 999),
                       4 * 24 * 32 * 80)
+
+
+def test_prefill_bytes_and_flops():
+    m = tiny_spec()
+    assert cost.matmul_params(m) == 2 * (192 + 256) + 8 * 32
+    # a token at context c: 2 × 1,152 weights, and 4 × 2 layers × 2 heads
+    # × 4 of attention per visible token
+    assert cost.token_flops(m, 3) == 2304 + 64 * 3
+    # a prompt of 4 tokens prefills its first 3, at contexts 1, 2 and 3
+    assert cost.prefill_flops(m, 4) == 3 * 2304 + 64 * (1 + 2 + 3)
+    assert cost.prefill_flops(tiny_spec(window=2), 4) \
+        == 3 * 2304 + 64 * (1 + 2 + 2)
+    # weights: 896 matrices, the 256 of the table read whole as the head,
+    # 5 LayerNorms of 16 float32; then 3 embedding rows of 8 and 3 tokens'
+    # K/V of 2 layers × 1 head × 4 × 2, written once
+    assert cost.param_bytes(m) == (896 + 256) * 2 + 5 * 16 * 4
+    assert cost.weight_bytes(m) == cost.param_bytes(m) == 2624
+    assert cost.kv_bytes_per_token(m) == 32
+    assert cost.prefill_bytes(m, 4) == 2624 + 3 * 8 * 2 + 3 * 32
+    # the weights count once per prompt, not once per token
+    assert cost.prefill_bytes(m, 9) - cost.prefill_bytes(m, 5) == 4 * 48
+    # a prompt of one token enters through the decode step alone
+    assert cost.prefill_flops(m, 1) == cost.prefill_bytes(m, 1) == 0
+    assert cost.prefill_seconds(m, 1, {"bf16_flops_per_s": 1.0,
+                                       "hbm_bytes_per_s": 1.0}) == 0
+    # the least time is the larger of the two
+    assert cost.prefill_seconds(m, 4, {"bf16_flops_per_s": 1.0,
+                                       "hbm_bytes_per_s": 1e9}) == 7296.0
+    assert cost.prefill_seconds(m, 4, {"bf16_flops_per_s": 1e9,
+                                       "hbm_bytes_per_s": 1.0}) == 2768.0

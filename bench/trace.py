@@ -10,9 +10,6 @@ spans (``bench.*``); ``reduce`` computes from that structure:
 * device busy time: the union of the intervals of the ``XLA Ops`` events
   of each device plane inside the window, averaged over the planes that ran
   anything;
-* the device time of the step program: the summed ``XLA Modules`` events
-  whose name starts with ``jit__step``, all of the trace's, since the
-  device runs nothing between the start of the trace and the span's open;
 * the device operations that took most time, by name;
 * the longest idle gaps of the device, each named by the harness span it
   fell in: ``admit step`` (a ``step_decode`` call that admitted requests,
@@ -29,8 +26,6 @@ from typing import Any
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
-MODULES_LINE = "XLA Modules"
-STEP_PROGRAM = "jit__step"
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.traced"
 GAP_NAMES = {"bench.step.admit": "admit step",
@@ -56,7 +51,7 @@ def load(path: str) -> dict[str, Any]:
         if plane.name.startswith(DEVICE_PREFIX):
             lines = {}
             for line in plane.lines:
-                if line.name in (OPS_LINE, MODULES_LINE):
+                if line.name == OPS_LINE:
                     lines[line.name] = [[_short(e.name), e.start_ns,
                                          e.duration_ns] for e in line.events]
             devices.append({"name": plane.name, "lines": lines})
@@ -88,8 +83,6 @@ def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
 class Reduction:
     window_s: float
     busy_s: float
-    step_device_s: float
-    step_programs: int
     device_ops: list[tuple[str, float]]
     idle_gaps: list[tuple[str, float]]
 
@@ -104,7 +97,7 @@ def reduce(trace: dict[str, Any]) -> Reduction:
     steps = sorted((s[1], s[1] + s[2], GAP_NAMES[s[0]])
                    for s in trace["spans"] if s[0] in GAP_NAMES)
 
-    busy, step_s, n_step = [], 0.0, 0
+    busy: list[float] = []
     by_op: dict[str, float] = {}
     gaps: list[tuple[float, float]] = []
     for dev in trace["devices"]:
@@ -117,10 +110,6 @@ def reduce(trace: dict[str, Any]) -> Reduction:
             by_op[name] = by_op.get(name, 0.0) + (b - a) * 1e-9
         merged = _union([(a, b) for a, b, _ in ops])
         busy.append(sum(b - a for a, b in merged) * 1e-9)
-        for name, a, d in dev["lines"].get(MODULES_LINE, []):
-            if name.startswith(STEP_PROGRAM):
-                step_s += d * 1e-9
-                n_step += 1
         edges = [w0] + [x for ab in merged for x in ab] + [w1]
         gaps += [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
     if not busy:
@@ -128,8 +117,6 @@ def reduce(trace: dict[str, Any]) -> Reduction:
     return Reduction(
         window_s=(w1 - w0) * 1e-9,
         busy_s=sum(busy) / len(busy),
-        step_device_s=step_s,
-        step_programs=n_step,
         device_ops=sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP],
         idle_gaps=[(_span_at((a + b) / 2, steps), (b - a) * 1e-9)
                    for a, b in sorted(gaps, key=lambda g: g[0] - g[1])[:TOP]])
