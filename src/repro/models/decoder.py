@@ -7,9 +7,9 @@ costs by the annotated trip count.
 
 Public surface (used by training/, serving/, launch/):
     init_params(cfg, key)                      -> params pytree
-    forward(params, cfg, tokens)               -> logits [+ aux]
-    prefill(params, cfg, tokens)               -> logits, cache
+    forward(params, cfg, tokens)               -> logits, aux
     init_decode_cache(cfg, batch, max_len)     -> cache pytree
+    prefill(params, cfg, cache, tokens, length) -> cache (paged K/V pools)
     decode_step(params, cfg, cache, tokens)    -> logits, cache
 """
 
@@ -102,7 +102,7 @@ def _layer_split(cfg: ModelConfig) -> tuple[int, int]:
 
 # ------------------------------------------------------------- layer bodies
 def _apply_layer(lp, cfg: ModelConfig, x, positions, moe: bool,
-                 q_chunk: int, kv_chunk: int, return_kv: bool):
+                 q_chunk: int, kv_chunk: int, prefill: bool):
     h = apply_norm(lp["norm1"], x, cfg.norm, cfg.norm_eps)
     if cfg.family == "mla_moe":
         attn_out = mla_mod.apply_mla(lp["attn"], cfg, h, positions,
@@ -110,32 +110,30 @@ def _apply_layer(lp, cfg: ModelConfig, x, positions, moe: bool,
         kv = None
     else:
         res = apply_attention(lp["attn"], cfg, h, positions, q_chunk=q_chunk,
-                              kv_chunk=kv_chunk, return_kv=return_kv)
-        attn_out, kv = res if return_kv else (res, None)
+                              kv_chunk=kv_chunk, return_kv=prefill)
+        attn_out, kv = res if prefill else (res, None)
     x = constrain(x + attn_out, "batch", "seq", "embed")
     h = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
     if moe:
-        y, aux = moe_mod.apply_moe(lp["moe"], cfg, h)
+        y, aux = moe_mod.apply_moe(lp["moe"], cfg, h, dropless=prefill)
     else:
         y, aux = apply_mlp(lp["mlp"], h, cfg.act), 0.0
     return constrain(x + y, "batch", "seq", "embed"), aux, kv
 
 
-# -------------------------------------------------------------------- forward
-def forward(params, cfg: ModelConfig, tokens, *, q_chunk: int = 512,
-            kv_chunk: int = 512, collect_kv: bool = False,
-            embeddings: Optional[jax.Array] = None, remat: bool = False):
-    """tokens: (B, S) int32 -> logits (B, S, V) [, aux, kv_stack].
+def _run_layers(params, cfg: ModelConfig, x, *, q_chunk: int = 512,
+                kv_chunk: int = 512, remat: bool = False,
+                prefill: bool = False):
+    """Every layer over x (B, S, d) at positions 0..S-1 -> (x, aux, kv).
 
-    ``embeddings`` overrides the token embedding (modality-frontend stub
-    path for the VLM/audio archs — precomputed patch/frame embeddings).
+    With ``prefill``, ``kv`` is each layer's post-RoPE (K, V), each
+    (L, B, S, KVH, hd) in layer order, and MoE layers route dropless, as
+    ``decode_step`` does: a dropped token would serve other tokens.
     """
-    x = params["embed"][tokens] if embeddings is None else embeddings
-    x = constrain(x, "batch", "seq", "embed")
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     aux_total = 0.0
-    kv_stacks = {}
+    kvs = []
 
     for name, moe in (("dense_layers", False), ("moe_layers", True)):
         if name not in params:
@@ -144,21 +142,35 @@ def forward(params, cfg: ModelConfig, tokens, *, q_chunk: int = 512,
         def body(carry, lp, moe=moe):
             x, aux = carry
             x, aux_l, kv = _apply_layer(lp, cfg, x, positions, moe,
-                                        q_chunk, kv_chunk, collect_kv)
+                                        q_chunk, kv_chunk, prefill)
             return (x, aux + aux_l), kv
 
         if remat:
             body = jax.checkpoint(body)   # store layer boundaries only
         (x, aux_total), kv = jax.lax.scan(body, (x, aux_total), params[name])
-        if collect_kv:
-            kv_stacks[name] = kv
+        kvs.append(kv)
 
+    kv = (tuple(jnp.concatenate(part) for part in zip(*kvs))
+          if prefill else None)
+    return x, aux_total, kv
+
+
+# -------------------------------------------------------------------- forward
+def forward(params, cfg: ModelConfig, tokens, *, q_chunk: int = 512,
+            kv_chunk: int = 512, embeddings: Optional[jax.Array] = None,
+            remat: bool = False):
+    """tokens: (B, S) int32 -> (logits (B, S, V), aux).
+
+    ``embeddings`` overrides the token embedding (modality-frontend stub
+    path for the VLM/audio archs — precomputed patch/frame embeddings).
+    """
+    x = params["embed"][tokens] if embeddings is None else embeddings
+    x = constrain(x, "batch", "seq", "embed")
+    x, aux_total, _ = _run_layers(params, cfg, x, q_chunk=q_chunk,
+                                  kv_chunk=kv_chunk, remat=remat)
     x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    if collect_kv:
-        return logits, aux_total, kv_stacks
-    return logits, aux_total
+    return x @ head, aux_total
 
 
 def loss_fn(params, cfg: ModelConfig, tokens, labels, *, q_chunk: int = 512,
@@ -305,8 +317,27 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
     return x @ head, new_cache
 
 
-def prefill(params, cfg: ModelConfig, tokens, *, q_chunk: int = 512,
-            kv_chunk: int = 512):
-    """Prefill pass: logits + per-layer K/V to be packed into the pools."""
-    return forward(params, cfg, tokens, q_chunk=q_chunk, kv_chunk=kv_chunk,
-                   collect_kv=(cfg.family != "mla_moe"))
+def prefill(params, cfg: ModelConfig, cache, tokens, length):
+    """One causal pass over a prompt at batch 1, its K/V written into a
+    batch-1 paged decode cache (``k_pool``/``v_pool`` layout only).
+
+    tokens: (1, S) int32, S a whole number of pages: the first ``length``
+    are the prompt's, the rest padding.  Padding lies beyond ``lengths``:
+    causal masking keeps the real positions from it, paged decode
+    attention masks it, and the next decode write takes its first slot.
+    Page j of the pass goes to the frame the page table names for slot j
+    (``page_pack``'s scatter).  Returns the cache with ``lengths`` =
+    ``length``.  No LM head: nothing reads a prompt's logits.
+    """
+    x = constrain(params["embed"][tokens], "batch", "seq", "embed")
+    _, _, (k, v) = _run_layers(params, cfg, x, prefill=True)
+    L, _, S, KVH, hd = k.shape
+    ps = cache["k_pool"].shape[2]
+    frames = cache["page_table"][0, :S // ps]
+
+    def pack(pool, new):
+        return pool.at[:, frames].set(new.reshape(L, S // ps, ps, KVH, hd))
+
+    return dict(cache, k_pool=pack(cache["k_pool"], k),
+                v_pool=pack(cache["v_pool"], v),
+                lengths=jnp.full_like(cache["lengths"], length))

@@ -5,6 +5,9 @@
     loss_fn(params, cfg, tokens, labels)      -> scalar
     init_decode_cache(cfg, batch, max_len)    -> cache pytree
     decode_step(params, cfg, cache, tokens)   -> (logits, cache)
+
+The decoder families add ``prefill(params, cfg, cache, tokens, length)``:
+one pass over a prompt into a paged K/V cache.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ def _decoder_api():
         forward=decoder.forward,
         loss_fn=decoder.loss_fn,
         init_decode_cache=decoder.init_decode_cache,
+        prefill=decoder.prefill,
         decode_step=decoder.decode_step,
     )
 

@@ -2,8 +2,9 @@
 
 The thesis' runtime loop, applied to inference serving:
 
-* requests arrive with a prompt; **prefill** computes the prompt's KV and
-  packs it into pool pages (``page_pack`` semantics);
+* requests arrive with a prompt; **prefill** computes the prompt's KV in
+  one pass and packs it into pool pages (``page_pack`` semantics), or,
+  for cache layouts without a paged K/V pool, token by token;
 * **decode** runs in lockstep over the active batch through the compiled
   paged-attention step; the page table handed to XLA names only resident
   frames — the engine (the "driver") resolves residency beforehand;
@@ -26,6 +27,7 @@ each ``step_decode`` (``step``), inside it ``serve.admit``,
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import functools
 from typing import Optional
@@ -48,9 +50,46 @@ from repro.serving.sampler import SamplerConfig, sample_token
 @functools.partial(jax.jit, static_argnums=1)
 def _step(params, cfg: ModelConfig, cache, tokens):
     """The compiled one-token step, shared by every engine on ``cfg``:
-    decode at ``(max_batch, 1)`` and prefill at ``(1, 1)`` are its two
-    shapes, each compiled once per process."""
+    decode at ``(max_batch, 1)``, and the token-by-token prefill of the
+    cache layouts that ``_prefill`` does not cover at ``(1, 1)``, each
+    shape compiled once per process."""
     return model_for(cfg).decode_step(params, cfg, cache, tokens)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _prefill(params, cfg: ModelConfig, cache, tokens, length):
+    """The compiled one-pass prefill, shared by every engine on ``cfg``:
+    one program per page bucket of the padded prompt."""
+    return model_for(cfg).prefill(params, cfg, cache, tokens, length)
+
+
+# glibc ``mallopt`` parameters, and the largest trim threshold set so far
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
+_MMAP_THRESHOLD = 32 << 20      # the highest glibc's moving threshold climbs
+_trim_threshold = 0
+
+
+def _keep_host_heap(step_bytes: int) -> None:
+    """Fix the C heap's thresholds for a round trip of ``step_bytes`` a step.
+
+    Each decode step converts the whole batch cache between device and
+    numpy, in fresh host buffers.  By default glibc moves its mmap and trim
+    thresholds after what the process freed before, so whether those
+    buffers are reused or mapped and faulted in anew, and with it the step's
+    time, followed the process's history (on a TPU v5e host, 270-480 ms a
+    step at StarCoder2-3B's widths).  Fixed thresholds make it steady:
+    buffers under 32 MB come from the heap, and the heap keeps a step's
+    worth of freed memory instead of handing it back between steps.  Only
+    ever raised; nothing where the C library has no ``mallopt``.
+    """
+    global _trim_threshold
+    trim = max(2 * _MMAP_THRESHOLD, 1 << (step_bytes - 1).bit_length())
+    mallopt = getattr(ctypes.CDLL(None), "mallopt", None)
+    if mallopt is None or trim <= _trim_threshold:
+        return
+    mallopt(_M_MMAP_THRESHOLD, _MMAP_THRESHOLD)
+    mallopt(_M_TRIM_THRESHOLD, trim)
+    _trim_threshold = trim
 
 
 @dataclasses.dataclass
@@ -65,7 +104,8 @@ class Request:
 @dataclasses.dataclass
 class EngineStats:
     prefills: int = 0
-    prefill_tokens: int = 0         # prompt tokens fed by batch-1 prefill
+    prefill_tokens: int = 0         # prompt tokens whose K/V prefill wrote
+    prefill_step_calls: int = 0     # batch-1 ``_step`` calls prefill made
     decode_steps: int = 0
     tokens_generated: int = 0
     spill_events: int = 0
@@ -111,6 +151,17 @@ class ServingEngine:
         # fixed (max_batch) decode shape; cache pools sized to the device
         # pool (shared across the batch via page table)
         self.cache = self.model.init_decode_cache(cfg, max_batch, max_len)
+        # the gather and the scatter convert the batch cache six times a step
+        _keep_host_heap(6 * sum(x.nbytes for x in
+                                jax.tree_util.tree_leaves(self.cache)))
+        # one prefill pass per prompt where the model has the program and
+        # the cache holds paged K/V pools; each page bucket compiles here,
+        # as set-up, so that no admission compiles
+        self._one_pass = (hasattr(self.model, "prefill")
+                          and "k_pool" in self.cache)
+        if self._one_pass:
+            for pages in range(1, -(-(max_len - 1) // ps) + 1):
+                self._prefill_pass(np.zeros(pages * ps, np.int32))
         self._seq_caches: dict = {}
         self.queue: list[Request] = []
         self.active: list[Request] = []
@@ -153,8 +204,21 @@ class ServingEngine:
                 self.active.append(r)
                 self.stats.prefills += 1
 
+    def _prefill_pass(self, head: np.ndarray):
+        """A batch-1 decode cache holding ``head``'s K/V, from one prefill
+        pass over it padded to whole pages (no pass for no tokens)."""
+        cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
+        if not len(head):
+            return cache
+        ps = self.cfg.kv_page_tokens
+        tokens = np.zeros((1, -(-len(head) // ps) * ps), np.int32)
+        tokens[0, :len(head)] = head
+        return _prefill(self.params, self.cfg, cache, tokens,
+                        np.int32(len(head)))
+
     def _prefill_sequence(self, r: Request) -> None:
-        """Token-by-token prefill of all but the last prompt token, through
+        """Prefill of all but the last prompt token into a batch-1 decode
+        cache: one pass on the paged layout, else token by token through
         the engine's compiled step at batch 1.
 
         The first decode step feeds the last prompt token and samples the
@@ -162,12 +226,18 @@ class ServingEngine:
         """
         with TraceAnnotation("serve.prefill", req_id=r.req_id,
                              prompt_tokens=len(r.prompt)):
-            cache = self.model.init_decode_cache(self.cfg, 1, self.max_len)
-            for t in r.prompt[:-1]:
-                _, cache = _step(self.params, self.cfg, cache,
-                                 jnp.asarray([[t]], jnp.int32))
+            head = r.prompt[:-1]
+            if self._one_pass:
+                cache = self._prefill_pass(head)
+            else:
+                cache = self.model.init_decode_cache(self.cfg, 1,
+                                                     self.max_len)
+                for t in head:
+                    _, cache = _step(self.params, self.cfg, cache,
+                                     jnp.asarray([[t]], jnp.int32))
+                self.stats.prefill_step_calls += len(head)
             self._seq_caches[r.req_id] = cache
-            self.stats.prefill_tokens += len(r.prompt) - 1
+            self.stats.prefill_tokens += len(head)
 
     # -------------------------------------------------------------- decode
     @staticmethod

@@ -129,3 +129,26 @@ def test_short_pool_serves_the_same_tokens():
     ktok = [read(types.SimpleNamespace(counters=c)) for c in counters]
     assert ktok[0] == 0 < ktok[1]
     assert counters[1]["spill_events"] > 0
+
+
+def test_engine_fixes_the_host_heap_for_its_round_trip(monkeypatch):
+    """The engine fixes glibc's mmap and trim thresholds once, the trim at
+    the power of two above what a step converts, and only ever raises it."""
+    from repro.serving import engine as engine_mod
+    calls = []
+    monkeypatch.setattr(engine_mod.ctypes, "CDLL", lambda _name: types.
+                        SimpleNamespace(mallopt=lambda *a: calls.append(a)))
+    monkeypatch.setattr(engine_mod, "_trim_threshold", 0)
+    mmap, trim = engine_mod._M_MMAP_THRESHOLD, engine_mod._M_TRIM_THRESHOLD
+    engine_mod._keep_host_heap(754_975_040)      # published widths, a step
+    assert calls == [(mmap, 32 << 20), (trim, 1 << 30)]
+    engine_mod._keep_host_heap(1 << 20)
+    assert len(calls) == 2
+    calls.clear()
+    _engine()                 # a smaller round trip lowers nothing
+    assert calls == [] and engine_mod._trim_threshold == 1 << 30
+    monkeypatch.setattr(engine_mod, "_trim_threshold", 0)
+    eng = _engine()
+    assert 6 * sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.cache)) \
+        < 64 << 20
+    assert calls == [(mmap, 32 << 20), (trim, 64 << 20)]

@@ -1,4 +1,4 @@
-"""Compile the serving path's kernels and decode step for a TPU v5e.
+"""Compile the serving path's kernels, decode step and prefill for a TPU v5e.
 
 Nothing runs: each test lowers and compiles at StarCoder2-3B widths for a
 described (not attached) v5e chip, so the TPU compiler's refusals (block
@@ -91,17 +91,41 @@ def test_page_scatter(cfg, one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def test_decode_step_full_width(cfg, one_chip):
-    model = model_for(cfg)
-    place = lambda s: _spec(s.shape, s.dtype, one_chip)   # noqa: E731
-    params = jax.tree_util.tree_map(place, jax.eval_shape(
-        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.tree_util.tree_map(place, jax.eval_shape(
-        lambda: model.init_decode_cache(cfg, BATCH, MAX_LEN)))
-    tokens = _spec((BATCH, 1), jnp.int32, one_chip)
-    step = jax.jit(lambda p, c, t: model.decode_step(p, cfg, c, t))
-    compiled, _ = _compile(step, params, cache, tokens)
+def _placed(fn, one_chip):
+    """The shapes ``fn`` would make, each placed on the described chip."""
+    return jax.tree_util.tree_map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                                  jax.eval_shape(fn))
+
+
+def _fits(compiled) -> bool:
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert used < HBM_BYTES
+    return used < HBM_BYTES
+
+
+def test_decode_step_full_width(cfg, one_chip):
+    model = model_for(cfg)
+    params = _placed(lambda: model.init_params(cfg, jax.random.PRNGKey(0)),
+                     one_chip)
+    cache = _placed(lambda: model.init_decode_cache(cfg, BATCH, MAX_LEN),
+                    one_chip)
+    tokens = _spec((BATCH, 1), jnp.int32, one_chip)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, cfg, c, t))
+    compiled, _ = _compile(step, params, cache, tokens)
+    assert _fits(compiled)
+
+
+def test_prefill_full_width(cfg, one_chip):
+    """The one-pass prefill of the longest page bucket, a whole 1,024-token
+    prompt, into a batch-1 paged cache."""
+    model = model_for(cfg)
+    params = _placed(lambda: model.init_params(cfg, jax.random.PRNGKey(0)),
+                     one_chip)
+    cache = _placed(lambda: model.init_decode_cache(cfg, 1, MAX_LEN),
+                    one_chip)
+    tokens = _spec((1, MAX_LEN), jnp.int32, one_chip)
+    length = _spec((), jnp.int32, one_chip)
+    fill = jax.jit(lambda p, c, t, n: model.prefill(p, cfg, c, t, n))
+    compiled, _ = _compile(fill, params, cache, tokens, length)
+    assert _fits(compiled)
